@@ -46,6 +46,7 @@ import numpy as np
 from repro.data import synth
 from repro.db import GraphDB
 from repro.faults import FaultPlan, InjectedPoison
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import OUTCOMES, AsyncServer
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
@@ -305,6 +306,7 @@ def _append_trajectory(entry: dict) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--universities", type=int, default=4)
     ap.add_argument("--replicas", type=int, default=3)

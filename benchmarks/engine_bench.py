@@ -80,6 +80,7 @@ from repro.data import synth
 from repro.db import GraphDB
 from repro.distributed import ctx as dctx
 from repro.engine.cost import ENGINES as ALL_ENGINES
+from repro.launch.compile_cache import enable_compile_cache
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
 BENCH_TOP = os.path.join(os.path.dirname(__file__), "..", "BENCH_engine.json")
@@ -430,6 +431,7 @@ def mutation(graph, *, engine: str = "auto", rates=(0.001, 0.01),
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--universities", type=int, default=8)
     ap.add_argument("--engine", default="auto",

@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.core import bitops
 from repro.kernels.bitmm import ops as bitmm_ops
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _best_s(fn, repeats: int) -> float:
@@ -242,6 +243,7 @@ def probe(fast: bool = False, backend: str | None = None):
 
 def main(argv: list[str] | None = None) -> int:
     """CLI: run the probe, print the spec, persist under ``results/machine/``."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--fast", action="store_true",
                     help="reduced CI sweep (fewer sizes/repeats)")

@@ -26,7 +26,11 @@ def _emit(section: str, rows: list[dict], time_key: str | None) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import kernels_bench, roofline, tables
+
+    enable_compile_cache()
 
     sections = sys.argv[1:] or [
         "table2", "table3", "table4", "table5", "iterations",

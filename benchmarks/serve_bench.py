@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.data import synth
 from repro.db import GraphDB
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import OUTCOMES, AsyncServer
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
@@ -201,6 +202,7 @@ def _append_trajectory(entry: dict) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--universities", type=int, default=4)
     ap.add_argument("--replicas", type=int, default=2)

@@ -134,8 +134,9 @@ class DualsimArch:
             return run_part
 
         def run_sparse(state, batch):
+            # the abstract state carries flat edge lists only
             return dualsim.solve_sparse(
-                state, max_sweeps=30, chi_spec=chi_spec
+                state, max_sweeps=30, chi_spec=chi_spec, impl="words"
             )
 
         return run_sparse

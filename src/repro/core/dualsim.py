@@ -929,9 +929,10 @@ def solve_sparse(
 
     ``impl`` picks the segmented-OR lowering: ``"words"`` (word-wise XLA,
     the CPU path), ``"kernel"`` (the blocked Pallas kernel over the
-    ``seg_*`` operand layout; ``interpret`` auto-enables off-TPU), or
+    ``seg_*`` operand layout; ``interpret`` auto-enables on CPU only), or
     ``None`` for backend auto-detection — kernel on accelerators, words on
-    CPU.  Operands without the blocked layout fall back to ``"words"``.
+    CPU.  ``"kernel"`` on operands without the blocked layout raises: a
+    caller that builds operands by hand asks for ``"words"`` explicitly.
     """
     from repro.kernels.segsum import kernel as segsum_kernel
     from repro.kernels.segsum import ref as segsum_ref
@@ -939,11 +940,14 @@ def solve_sparse(
     n = ops.init.shape[-1]
     if impl is None:
         impl = "words" if jax.default_backend() == "cpu" else "kernel"
-    # trace-ok: seg_win's None-ness is pytree *structure*, static under jit
-    if impl == "kernel" and ops.seg_win is None:
-        impl = "words"  # hand-built / abstract Operands: flat lists only
     if impl not in ("words", "kernel"):
         raise ValueError(f"unknown sparse impl {impl!r}")
+    # trace-ok: seg_win's None-ness is pytree *structure*, static under jit
+    if impl == "kernel" and ops.seg_win is None:
+        raise ValueError(
+            "impl='kernel' needs the blocked segmented-OR layout (seg_*); "
+            "build operands with make_sparse_operands or pass impl='words'"
+        )
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
 

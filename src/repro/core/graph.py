@@ -20,9 +20,9 @@ import numpy as np
 
 from . import bitops
 
-# Hard budget on any [n, n] plane: past this, a dense adjacency (or the
-# transient dense build inside packed_adjacency) cannot be materialized at
-# all — construction raises MemoryError instead of OOMing the host, and
+# Hard budget on the dense-layout tier: past this, a dense (or bit-packed)
+# [n, n] adjacency cannot be materialized at all — construction raises
+# MemoryError instead of OOMing the host, and
 # engine.cost refuses the dense-layout engine tier before it gets here
 # (ISSUE 8: the RDF workload runs where this is structurally impossible).
 DENSE_ADJ_MAX_BYTES = 2 << 30
@@ -143,6 +143,18 @@ class Graph:
     # ------------------------------------------------------------------ #
     # dense / packed adjacency (MXU + Pallas engines)
     # ------------------------------------------------------------------ #
+    def _check_dense_budget(self) -> None:
+        if self.n_nodes * self.n_nodes > DENSE_ADJ_MAX_BYTES:
+            raise MemoryError(
+                f"dense [n, n] adjacency at n={self.n_nodes} needs "
+                f"{self.n_nodes * self.n_nodes} bytes > budget "
+                f"{DENSE_ADJ_MAX_BYTES}; use the edge-list engines"
+            )
+
+    def _oriented(self, a: int, backward: bool) -> tuple[np.ndarray, np.ndarray]:
+        e = self.edges_for_label(a)
+        return (e[:, 1], e[:, 0]) if backward else (e[:, 0], e[:, 1])
+
     def dense_adjacency(self, a: int, backward: bool = False) -> np.ndarray:
         """bool[n, n] forward (or backward) adjacency matrix for label a.
 
@@ -151,23 +163,26 @@ class Graph:
         exist, and failing here (cheaply, before allocation) is what the
         ``--rdf`` bench asserts.
         """
-        if self.n_nodes * self.n_nodes > DENSE_ADJ_MAX_BYTES:
-            raise MemoryError(
-                f"dense [n, n] adjacency at n={self.n_nodes} needs "
-                f"{self.n_nodes * self.n_nodes} bytes > budget "
-                f"{DENSE_ADJ_MAX_BYTES}; use the edge-list engines"
-            )
-        e = self.edges_for_label(a)
+        self._check_dense_budget()
+        rows, cols = self._oriented(a, backward)
         m = np.zeros((self.n_nodes, self.n_nodes), dtype=bool)
-        if backward:
-            m[e[:, 1], e[:, 0]] = True
-        else:
-            m[e[:, 0], e[:, 1]] = True
+        m[rows, cols] = True
         return m
 
     def packed_adjacency(self, a: int, backward: bool = False) -> np.ndarray:
-        """uint32[n, ceil(n/32)] bit-packed adjacency for label a."""
-        return np.asarray(bitops.pack(self.dense_adjacency(a, backward)))
+        """uint32[n, ceil(n/32)] bit-packed adjacency for label a.
+
+        Built on the host straight from the edge list, in the
+        :func:`repro.core.bitops.pack` layout, with no bool [n, n] plane in
+        between; the dense tier's budget still applies.
+        """
+        self._check_dense_budget()
+        rows, cols = self._oriented(a, backward)
+        out = np.zeros((self.n_nodes, bitops.packed_width(self.n_nodes)),
+                       np.uint32)
+        bits = np.left_shift(np.uint32(1), (cols % bitops.WORD).astype(np.uint32))
+        np.bitwise_or.at(out, (rows, cols // bitops.WORD), bits)
+        return out
 
     def summary_fwd(self, a: int) -> np.ndarray:
         """Paper's f^a: bool[n], bit i set iff node i has an outgoing a-edge."""

@@ -56,8 +56,8 @@ throughput, plus measured per-call overheads.  No engine-selection path
 reads a hand-tuned constant once a spec is present.
 
 Feasibility is a HARD gate, not a preference: any engine whose *build*
-path materializes an ``[n, n]`` plane — dense itself, and the packed tier,
-whose ``graph.packed_adjacency`` packs through a transient dense build —
+path builds an ``[n, n]`` plane — dense itself, and the packed tier,
+whose ``graph.packed_adjacency`` holds to the same budget —
 is refused outright once ``n * n`` exceeds the byte budget
 (``graph.DENSE_ADJ_MAX_BYTES``).  The gate depends only on graph shape,
 never on calibration: no spec, however distorted, can un-refuse an engine
@@ -116,10 +116,9 @@ RESUME_MAX_DELTA_FRACTION = 0.25  # past this, the old chi is mostly reseeded
 def dense_tier_feasible(n: int) -> bool:
     """Whether any ``[n, n]`` operand plane may be materialized at all.
 
-    Gates dense AND both packed engines: ``graph.packed_adjacency`` packs
-    through a transient dense ``[n, n]`` build, so the packed tier is just
-    as impossible past the budget even though its *resident* operand is 32x
-    smaller.
+    Gates dense AND both packed engines: ``graph.packed_adjacency`` refuses
+    the same ``n * n`` budget, so the packed tier is just as impossible past
+    it even though its operand is 32x smaller.
     """
     return n * n <= DENSE_TIER_MAX_BYTES
 

@@ -108,7 +108,6 @@ class CompiledPlan:
         self.mesh = mesh
         self.incremental = incremental
         n_devices = int(mesh.devices.size) if mesh is not None else 1
-        self._n_devices = n_devices
         self.n_blocks = n_blocks if n_blocks is not None else max(n_devices, 1)
         # chi is [V, n]: shard the node axis across every mesh axis; the
         # V axis (variables) stays replicated — it is tiny and irregular
@@ -159,6 +158,10 @@ class CompiledPlan:
             i for i in range(batch) for _ in per_part
         ]
 
+        # the lowering follows the platform the operands live on; ``backend``
+        # only prices the engine choice, so an override can never put a CPU
+        # lowering (interpret mode, the word-wise XLA path) on an accelerator
+        on_cpu = jax.default_backend() == "cpu"
         self.cost: cost_mod.CostEstimate | None = None
         if engine == "auto":
             self.cost = cost_mod.choose_engine(
@@ -174,39 +177,38 @@ class CompiledPlan:
             self.operands = dualsim.make_packed_operands(self.csoi, db, adj_cache)
             # compiled Pallas kernel on accelerators; interpret only on CPU
             # (the cost model prices the two regimes very differently)
-            solver = functools.partial(
-                dualsim.solve_packed, interpret=(backend == "cpu")
-            )
+            solver = functools.partial(dualsim.solve_packed, interpret=on_cpu)
         elif engine == "packed_fused":
             self.operands = dualsim.make_packed_operands(self.csoi, db, adj_cache)
             # fused Pallas kernel on accelerators; on CPU the word-wise XLA
-            # lowering (kernel emulation would cost ~9x — DESIGN.md Sect. 9).
-            # Resolved here, not via impl=None, because plans honor an
-            # Engine-level ``backend`` override rather than the process
-            # default the solver's auto-detection would consult.
+            # lowering (kernel emulation would cost ~9x — DESIGN.md Sect. 9)
             solver = functools.partial(
                 dualsim.solve_packed_fused,
-                impl=("words" if backend == "cpu" else "kernel"),
+                impl=("words" if on_cpu else "kernel"),
             )
         elif engine in ("sparse", "jacobi_packed"):
             # both sparse modes run the segmented-OR sweep over bit-packed
-            # chi (ISSUE 8).  The lowering is resolved here like
-            # packed_fused's: blocked Pallas kernel on accelerators, the
-            # word-wise XLA path on CPU — plans honor an Engine-level
-            # ``backend`` override rather than the process default the
-            # solver's auto-detection would consult.
+            # chi, lowered like packed_fused's: blocked Pallas kernel on
+            # accelerators, the word-wise XLA path on CPU
             self.operands = dualsim.make_sparse_operands(self.csoi, db, adj_cache)
             solver = functools.partial(
                 dualsim.solve_sparse,
                 mode=("jacobi_packed" if engine == "jacobi_packed" else "gs"),
-                impl=("words" if backend == "cpu" else "kernel"),
+                impl=("words" if on_cpu else "kernel"),
+                interpret=on_cpu,
                 chi_spec=self.chi_spec,
             )
         elif engine == "partitioned":
             self.operands = dualsim.make_partitioned_operands(
                 self.csoi, db, self.n_blocks, adj_cache
             )
-            if mesh is not None and self.n_blocks % n_devices == 0:
+            if mesh is not None:
+                if self.n_blocks % n_devices:
+                    raise ValueError(
+                        f"n_blocks={self.n_blocks} does not divide over the "
+                        f"{n_devices}-device mesh; the partitioned operands "
+                        "would stay on one device"
+                    )
                 self.operands = _shard_partitioned_operands(
                     self.operands, mesh, self.chi_spec
                 )
@@ -258,7 +260,8 @@ class CompiledPlan:
             chi, sweeps = solver(ops)
             return chi[:, :n_nodes], sweeps
 
-        self._run = jax.jit(_run)
+        # the jitted fixpoint: ``fixpoint(*fixpoint_inputs(bindings))``
+        self.fixpoint = jax.jit(_run)
         self.metrics.build_seconds = time.perf_counter() - t0
 
     # ------------------------------------------------------------------ #
@@ -296,6 +299,24 @@ class CompiledPlan:
                 rows[j, node] = True
         return rows
 
+    def fixpoint_inputs(
+        self, bindings: Sequence[tuple[str, ...]]
+    ) -> tuple[dualsim.Operands, jax.Array, jax.Array]:
+        """``(operands, const_rows, chi0)`` of a cold solve of ``bindings``.
+
+        What :meth:`execute` passes to :attr:`fixpoint` when no warm start
+        is staged; lowering ``fixpoint`` on them shows the whole compiled
+        fixpoint, kernels included, without running it.
+        """
+        rows = self.const_rows(bindings)
+        if self._packed_chi:
+            # packed engines take everything as uint32 words: constants,
+            # init, warm starts — 8x less host->device traffic per request
+            rows = bitops.pack_np(rows)
+        # cold: chi0 is the init itself, so the AND with it is an identity
+        chi0 = self.operands.init_packed if self._packed_chi else self.operands.init
+        return self.operands, jnp.asarray(rows), chi0
+
     def execute(
         self, bindings: Sequence[tuple[str, ...]]
     ) -> tuple[np.ndarray, int]:
@@ -307,26 +328,16 @@ class CompiledPlan:
         start for exactly these constants, the solve resumes from it
         instead of the Eq.-13 init (same fixpoint, far fewer sweeps).
         """
-        rows = self.const_rows(bindings)
-        if self._packed_chi:
-            # packed engines take everything as uint32 words: constants,
-            # init, warm starts — 8x less host->device traffic per request
-            rows = bitops.pack_np(rows)
-        rows = jnp.asarray(rows)
+        ops, rows, chi0 = self.fixpoint_inputs(bindings)
         key = tuple(bindings)
         warm = self._warm.pop(key, None)
-        cold_identity = (
-            self.operands.init_packed if self._packed_chi else self.operands.init
-        )
-        if warm is None:
-            chi0 = cold_identity  # cold: AND with init is an identity
-        else:
-            width = cold_identity.shape[-1]
+        if warm is not None:
+            width = chi0.shape[-1]
             if warm.shape[-1] != width:  # partitioned block padding
                 warm = np.pad(warm, ((0, 0), (0, width - warm.shape[-1])))
             chi0 = jnp.asarray(warm)
             self.metrics.warm_resumes += 1
-        chi, sweeps = self._run(self.operands, rows, chi0)
+        chi, sweeps = self.fixpoint(ops, rows, chi0)
         self.metrics.executions += 1
         chi, sweeps = np.asarray(chi), int(sweeps)
         self.last_sweeps = sweeps
@@ -374,11 +385,7 @@ class CompiledPlan:
             n_blocks=self.n_blocks,
             adj_cache=cache,
         )
-        if (
-            self.engine == "partitioned"
-            and self.mesh is not None
-            and self.n_blocks % self._n_devices == 0
-        ):
+        if self.engine == "partitioned" and self.mesh is not None:
             self.operands = _shard_partitioned_operands(
                 self.operands, self.mesh, self.chi_spec
             )

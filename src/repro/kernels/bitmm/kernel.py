@@ -26,6 +26,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _or_select(acc, bit, row):
+    """``acc | (row if bit else 0)`` with ``bit`` a 0/1 ``[V, 1]`` column.
+
+    ``0 - bit`` turns the bit into an all-ones or all-zero word mask, so the
+    select is one AND per word and the OR-reduction over the contraction
+    axis becomes a chain of elementwise ORs — no ``lax.reduce`` with a
+    custom monoid, which Mosaic does not lower.
+    """
+    return acc | ((jnp.uint32(0) - bit) & row)
+
+
 def _bitmm_kernel(x_ref, a_ref, o_ref):
     i = pl.program_id(1)
 
@@ -35,14 +46,11 @@ def _bitmm_kernel(x_ref, a_ref, o_ref):
 
     x = x_ref[...]  # [V, BI] uint32 (0/1 flags)
     a = a_ref[...]  # [BI, BJW] uint32 packed words
-    # rows of A where the frontier bit is set, OR-reduced over the block.
-    masked = jnp.where(
-        (x != 0)[:, :, None], a[None, :, :], jnp.uint32(0)
-    )  # [V, BI, BJW]
-    acc = jax.lax.reduce(
-        masked, jnp.uint32(0), jax.lax.bitwise_or, (1,)
-    )  # [V, BJW]
-    o_ref[...] = jnp.bitwise_or(o_ref[...], acc)
+    # rows of A where the frontier bit is set, OR-folded over the block
+    acc = o_ref[...]  # [V, BJW]
+    for r in range(x.shape[1]):
+        acc = _or_select(acc, x[:, r:r + 1], a[r:r + 1, :])
+    o_ref[...] = acc
 
 
 def _bitmm_apply_kernel(xc_ref, a_ref, f_ref, xe_ref, o_ref, chg_ref):
@@ -50,8 +58,10 @@ def _bitmm_apply_kernel(xc_ref, a_ref, f_ref, xe_ref, o_ref, chg_ref):
 
     Grid (J, I), I innermost.  ``o_ref`` doubles as the y accumulator: for
     i < I-1 it holds the partial packed product; the last contraction step
-    turns it into the updated chi tile in place and ORs the changed words
+    turns it into the updated chi tile in place and ORs the moved words
     into ``chg_ref`` — one revisited output tile, no scratch buffer.
+    ``chg_ref`` is a ``[V, BJW]`` word tile revisited by every grid step;
+    the caller ORs it down to the scalar changed flag.
     """
     j, i = pl.program_id(0), pl.program_id(1)
     ni = pl.num_programs(1)
@@ -65,35 +75,29 @@ def _bitmm_apply_kernel(xc_ref, a_ref, f_ref, xe_ref, o_ref, chg_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     xw = xc_ref[...]  # [V, BIW] packed chi words of the contraction block
-    a = a_ref[...]  # [1, BIW, 32, BJW] packed adjacency tile, word-split rows
-    # frontier bits of the block, extracted word-wise on the VPU (bit s of
-    # word w is contraction row 32*w + s — matching a's host-side reshape)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
-    bits = (xw[:, :, None] >> shifts) & jnp.uint32(1)  # [V, BIW, 32]
-    masked = jnp.where(
-        (bits != 0)[..., None], a, jnp.uint32(0)
-    )  # [V, BIW, 32, BJW]
-    acc = jax.lax.reduce(masked, jnp.uint32(0), jax.lax.bitwise_or, (1, 2))
-    o_ref[...] = jnp.bitwise_or(o_ref[...], acc)
+    # frontier bits of the block, extracted word-wise on the VPU: bit s of
+    # word w is contraction row 32*w + s, matching a's host-side reshape
+    acc = o_ref[...]
+    for w in range(xw.shape[1]):
+        col = xw[:, w:w + 1]  # [V, 1]
+        a_w = a_ref[w]  # [32, BJW] packed adjacency rows 32*w .. 32*w+31
+        for s in range(32):
+            acc = _or_select(acc, (col >> s) & jnp.uint32(1), a_w[s:s + 1, :])
+    o_ref[...] = acc
 
     @pl.when(i == ni - 1)
     def _combine():
         y = o_ref[...]  # [V, BJW] finished packed product chi ×b A
         f = f_ref[...]  # [V, V] lhs-rhs inequality flags
         # chi[l] &= AND_{r: f[l,r]} y[r]  ==  chi[l] &= ~OR_{r: f[l,r]} ~y[r]
-        viol = jnp.where(
-            (f != 0)[:, :, None], jnp.bitwise_not(y)[None, :, :], jnp.uint32(0)
-        )  # [V(lhs), V(rhs), BJW]
-        bad = jax.lax.reduce(viol, jnp.uint32(0), jax.lax.bitwise_or, (1,))
+        not_y = jnp.bitwise_not(y)
+        bad = jnp.zeros_like(y)
+        for r in range(f.shape[1]):
+            bad = _or_select(bad, f[:, r:r + 1], not_y[r:r + 1, :])
         old = xe_ref[...]  # [V, BJW] chi tile being updated
         new = jnp.bitwise_and(old, jnp.bitwise_not(bad))
         o_ref[...] = new
-        delta = jax.lax.reduce(
-            jnp.bitwise_xor(new, old), jnp.uint32(0), jax.lax.bitwise_or, (0, 1)
-        )
-        chg_ref[...] = jnp.bitwise_or(
-            chg_ref[...], jnp.full((1, 1), delta, jnp.uint32)
-        )
+        chg_ref[...] = chg_ref[...] | jnp.bitwise_xor(new, old)
 
 
 @functools.partial(
@@ -126,38 +130,46 @@ def bitmm_apply_packed(
     np_ = -(-n // block_i) * block_i
     nwp = -(-nw // block_jw) * block_jw
     biw = block_i // 32
+    nbi = np_ // block_i
     # chi plays two roles: contraction input (its bits select A rows, so its
     # word axis pads to np_/32) and elementwise input (tiles like the
     # output, padding to nwp).  Zero padding is the OR/AND identity in both.
-    xc = jnp.zeros((vp, np_ // 32), jnp.uint32).at[:v, :nw].set(chi_packed)
+    # The contraction words go block-major, [I, V, BIW]: each grid step
+    # reads one whole (V, BIW) trailing slab, which the TPU tiling accepts
+    # for any BIW (a (V, BIW) window of a wider word axis would need BIW to
+    # be a multiple of 128 lanes).
+    xc = (
+        jnp.zeros((vp, np_ // 32), jnp.uint32).at[:v, :nw].set(chi_packed)
+        .reshape(vp, nbi, biw).transpose(1, 0, 2)
+    )
     xe = jnp.zeros((vp, nwp), jnp.uint32).at[:v, :nw].set(chi_packed)
     a_p = jnp.zeros((np_, nwp), jnp.uint32).at[:n, :nw].set(a_packed)
     # row 32*w + s of block b lands at [b, w, s, :]: the kernel's bit
     # extraction indexes words, never reshapes inside the kernel
-    a4 = a_p.reshape(np_ // block_i, biw, 32, nwp)
+    a4 = a_p.reshape(nbi, biw, 32, nwp)
     f_p = jnp.zeros((vp, vp), jnp.uint32).at[:v, :v].set(lhs_flags)
 
-    grid = (nwp // block_jw, np_ // block_i)
+    grid = (nwp // block_jw, nbi)
     chi_new, changed = pl.pallas_call(
         _bitmm_apply_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((vp, biw), lambda j, i: (0, i)),
-            pl.BlockSpec((1, biw, 32, block_jw), lambda j, i: (i, 0, 0, j)),
+            pl.BlockSpec((None, vp, biw), lambda j, i: (i, 0, 0)),
+            pl.BlockSpec((None, biw, 32, block_jw), lambda j, i: (i, 0, 0, j)),
             pl.BlockSpec((vp, vp), lambda j, i: (0, 0)),
             pl.BlockSpec((vp, block_jw), lambda j, i: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((vp, block_jw), lambda j, i: (0, j)),
-            pl.BlockSpec((1, 1), lambda j, i: (0, 0)),
+            pl.BlockSpec((vp, block_jw), lambda j, i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((vp, nwp), jnp.uint32),
-            jax.ShapeDtypeStruct((1, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((vp, block_jw), jnp.uint32),
         ],
         interpret=interpret,
     )(xc, a4, f_p, xe)
-    return chi_new[:v, :nw], changed[0, 0]
+    return chi_new[:v, :nw], jnp.any(changed != 0).astype(jnp.uint32)
 
 
 @functools.partial(

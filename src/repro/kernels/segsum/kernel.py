@@ -252,8 +252,8 @@ def segor_blocks(
         num_scalar_prefetch=1,
         grid=(g,),
         in_specs=[
-            pl.BlockSpec((1, be), lambda i, win: (i, 0)),
-            pl.BlockSpec((1, be, vp), lambda i, win: (i, 0, 0)),
+            pl.BlockSpec((None, 1, be), lambda i, win: (i, 0, 0)),
+            pl.BlockSpec((None, be, vp), lambda i, win: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((block_w, vp), lambda i, win: (win[i], 0)),
     )
@@ -266,13 +266,12 @@ def segor_blocks(
             out_ref[...] = jnp.zeros_like(out_ref)
 
         base = win_ref[i] * block_n
-        local = seg_ref[0] - base  # [BE]; pad sentinels land >= block_n
+        local = seg_ref[...] - base  # [1, BE]; pad sentinels land >= block_n
         onehot = (
-            local[None, :]
-            == jax.lax.broadcasted_iota(jnp.int32, (block_n, be), 0)
+            local == jax.lax.broadcasted_iota(jnp.int32, (block_n, be), 0)
         ).astype(jnp.float32)
         counts = jnp.dot(
-            onehot, val_ref[0], preferred_element_type=jnp.float32
+            onehot, val_ref[...], preferred_element_type=jnp.float32
         )  # [block_n, VP]
         bits = (counts > 0).astype(jnp.float32)
         # exact f32 bit-pack: words[w] = sum_s 2^s * bits[32w + s], split
@@ -291,15 +290,15 @@ def segor_blocks(
         )
         lo = jnp.dot(lo_w, bits, preferred_element_type=jnp.float32)
         hi = jnp.dot(hi_w, bits, preferred_element_type=jnp.float32)
-        words = lo.astype(jnp.uint32) | (
-            hi.astype(jnp.uint32) << jnp.uint32(16)
-        )
+        # f32 -> int32 is exact (every half is < 2**16); the high half's
+        # shift into the sign bit is fine, the caller reinterprets as uint32
+        words = lo.astype(jnp.int32) | (hi.astype(jnp.int32) << 16)
         out_ref[...] = out_ref[...] | words
 
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_pad // 32, vp), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((n_pad // 32, vp), jnp.int32),
         interpret=interpret,
-    )(win, seg_b, vals_p)
-    return out[:nw, :v].T
+    )(win, seg_b.reshape(g, 1, be), vals_p)
+    return jax.lax.bitcast_convert_type(out[:nw, :v].T, jnp.uint32)

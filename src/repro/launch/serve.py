@@ -33,12 +33,14 @@ import argparse
 import asyncio
 import time
 
+import jax
 import numpy as np
 
 from repro.data import synth
 from repro.db import GraphDB
 from repro.distributed import ctx as dctx
 from repro.engine.cost import ENGINES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import AsyncServer
 
 QUERY = "{{ ?d subOrganizationOf {uni} . ?s memberOf ?d }}"
@@ -147,18 +149,22 @@ def main() -> None:
                     choices=["auto", *ENGINES],
                     help="fixpoint engine; 'auto' = cost-based selection")
     ap.add_argument("--devices", type=int, default=0,
-                    help="shard over a mesh of this many (simulated host) "
-                         "devices; 0 = no mesh")
+                    help="shard over a mesh of this many devices (simulated "
+                         "host devices under JAX_PLATFORMS=cpu); 0 = no mesh")
     ap.add_argument("--mutate", action="store_true",
                     help="mutate mid-stream: a shape-stable delete/re-insert "
                          "churn (warm-resumed plans) plus a dictionary-"
                          "growing insert (cold invalidation)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.devices > 1:
-        # must run before the first JAX computation initializes the backend
-        dctx.force_host_device_count(args.devices)
+        if jax.config.jax_platforms == "cpu":
+            # simulated host devices, only where JAX is pinned to the CPU;
+            # must run before the first JAX computation initializes the
+            # backend.  On an accelerator the mesh takes the real chips.
+            dctx.force_host_device_count(args.devices)
         mesh = dctx.node_mesh(args.devices)
 
     db = GraphDB(synth.lubm_like(n_universities=8, seed=0),

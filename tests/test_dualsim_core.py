@@ -281,6 +281,26 @@ def test_sparse_impls_match(mode):
     assert int(it_k) == int(it_w)
 
 
+def test_sparse_kernel_without_blocked_layout_raises():
+    """impl="kernel" on operands lacking the blocked segmented-OR layout is
+    an error, never a quiet drop to the word-wise lowering; callers that
+    build operands by hand ask for impl="words" explicitly."""
+    import dataclasses
+
+    db = synth.random_graph(40, 2, 90, seed=5)
+    pat = synth.random_pattern(3, 2, 3, seed=5)
+    c = soi.compile_soi(dualsim.pattern_graph_soi(pat), db)
+    flat = dataclasses.replace(
+        dualsim.make_sparse_operands(c, db),
+        seg_src_b=None, seg_dst_b=None, seg_win=None,
+    )
+    with pytest.raises(ValueError, match="blocked segmented-OR layout"):
+        dualsim.solve_sparse(flat, impl="kernel")
+    ref, _ = dualsim.solve_worklist(c, db)
+    chi, _ = dualsim.solve_sparse(flat, impl="words")
+    assert np.array_equal(np.asarray(chi), ref)
+
+
 # --------------------------------------------------------------------- #
 # packed-chi invariants: the while_loop never packs or unpacks (ISSUE 5).
 # The jaxpr machinery lives in tools.reprolint.dynamic so the same check
@@ -335,6 +355,36 @@ def test_edge_engines_while_body_is_pack_free():
         assert bodies
         for body in bodies:
             assert rl_dynamic.check_edge_body(body) == []
+
+
+def test_fused_body_audit_flags_a_pack_round_trip():
+    """The audit is not vacuous: a while body that unpacks and re-packs
+    chi, or carries it as a bool plane, is reported."""
+    import jax
+
+    from repro.core import bitops
+
+    chi = jnp.zeros((3, 3), jnp.uint32)
+
+    def round_trip(c):
+        def body(state):
+            x, i = state
+            return bitops.pack(bitops.unpack(x, 70)), i + 1
+
+        return jax.lax.while_loop(lambda s: s[1] < 2, body, (c, 0))
+
+    (body,) = rl_dynamic._while_bodies(round_trip, chi)
+    found = " ".join(rl_dynamic.check_fused_body(body))
+    assert "pack/unpack primitives" in found and "bool" in found
+
+    def bool_carry(c):
+        return jax.lax.while_loop(
+            lambda s: s[1] < 2, lambda s: (~s[0], s[1] + 1),
+            (bitops.unpack(c, 70), 0),
+        )
+
+    (body,) = rl_dynamic._while_bodies(bool_carry, chi)
+    assert any("bool chi plane" in v for v in rl_dynamic.check_fused_body(body))
 
 
 def test_dynamic_cross_check_runs_clean():

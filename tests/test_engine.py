@@ -158,6 +158,20 @@ def test_dense_tier_hard_gate_matches_graph_budget():
     assert g2.dense_adjacency(0).shape == (n - 1, n - 1)
 
 
+@pytest.mark.parametrize("backward", [False, True])
+def test_packed_adjacency_packs_the_dense_plane(backward):
+    """Built from the edge list, the packed adjacency is bit for bit the
+    ``bitops.pack`` of the dense plane, pad bits of the last word zero."""
+    from repro.core import bitops
+
+    g = synth.random_graph(n_nodes=70, n_labels=2, n_edges=300, seed=3)
+    for a in range(g.n_labels):
+        want = np.asarray(bitops.pack(g.dense_adjacency(a, backward)))
+        got = g.packed_adjacency(a, backward)
+        assert got.dtype == np.uint32
+        assert np.array_equal(got, want)
+
+
 # --------------------------------------------------------------------- #
 # batcher
 # --------------------------------------------------------------------- #
@@ -360,6 +374,23 @@ def test_partitioned_warm_rebind_no_recompile_no_retrace(lubm):
         assert r.cache_hit
     assert eng.cache.misses == builds
     assert plan.metrics.traces == traces
+
+
+def test_partitioned_mesh_must_divide_n_blocks(lubm):
+    """A mesh whose size does not divide the destination blocks raises
+    instead of leaving every partitioned operand on the first device."""
+    from jax.sharding import Mesh
+
+    from repro.distributed import ctx as dctx
+    from repro.engine.plan import CompiledPlan
+
+    mesh = Mesh(np.asarray(jax.devices()[:1] * 3), (dctx.NODE_AXIS,))
+    template = canonicalize(
+        sparql.parse("{ ?d subOrganizationOf Univ0 . ?s memberOf ?d }")
+    ).template
+    with pytest.raises(ValueError, match="does not divide"):
+        CompiledPlan(template, lubm, engine="partitioned", mesh=mesh,
+                     n_blocks=4)
 
 
 @pytest.mark.skipif(

@@ -206,3 +206,28 @@ def test_lubm_stream_matches_lubm_shape():
     assert set(g.label_names) == set(ref.label_names)
     assert abs(g.n_nodes - ref.n_nodes) / ref.n_nodes < 0.05
     assert abs(g.n_edges - ref.n_edges) / ref.n_edges < 0.05
+
+
+@pytest.mark.parametrize("env_dir", [None, "/somewhere/else"])
+def test_compile_cache_placement(env_dir, monkeypatch):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says (JAX reads the variable; nothing is set in code), and otherwise
+    to the fixed ``<checkout>/.jax_cache``; importing sets nothing."""
+    from repro.launch import compile_cache
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            assert got == os.path.join(checkout, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

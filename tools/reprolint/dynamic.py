@@ -45,7 +45,7 @@ EDGE_FORBIDDEN = {"reduce_sum"}
 
 def sub_jaxprs(param):
     """Yield jaxprs nested inside an equation parameter."""
-    import jax.core as jcore
+    from jax.extend import core as jcore
 
     if isinstance(param, jcore.ClosedJaxpr):
         yield param.jaxpr
