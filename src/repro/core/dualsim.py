@@ -665,10 +665,12 @@ def _edge_bits(frontier_p: jax.Array, src: jax.Array) -> jax.Array:
     """Per-edge source bits gathered straight out of packed frontier words.
 
     ``int8 [V, E]``: bit ``src[e] % 32`` of word ``src[e] // 32`` — the
-    gathered table is 32x smaller than a boolean frontier.
+    gathered table is 32x smaller than a boolean frontier.  Its device ops
+    run under ``jax.named_scope("edge_bits")``.
     """
-    word = frontier_p[:, src // 32]  # [V, E] uint32
-    return ((word >> (src % 32).astype(jnp.uint32)) & 1).astype(jnp.int8)
+    with jax.named_scope("edge_bits"):
+        word = frontier_p[:, src // 32]  # [V, E] uint32
+        return ((word >> (src % 32).astype(jnp.uint32)) & 1).astype(jnp.int8)
 
 
 def _warm_init(ops: Operands, chi0: jax.Array | None) -> jax.Array:
@@ -952,14 +954,17 @@ def solve_sparse(
         interpret = jax.default_backend() == "cpu"
 
     def propagate(frontier_p: jax.Array, m: int) -> jax.Array:
+        # device ops: the gather under "edge_bits", the OR under "segor"
         if impl == "kernel":
             bits = _edge_bits(frontier_p, ops.seg_src_b[m])  # [V, G, BE]
-            return segsum_kernel.segor_blocks(
-                bits.transpose(1, 2, 0), ops.seg_dst_b[m], ops.seg_win[m],
-                num_segments=n, interpret=interpret,
-            )
+            with jax.named_scope("segor"):
+                return segsum_kernel.segor_blocks(
+                    bits.transpose(1, 2, 0), ops.seg_dst_b[m], ops.seg_win[m],
+                    num_segments=n, interpret=interpret,
+                )
         msgs = _edge_bits(frontier_p, ops.edge_src[m])  # int8 [V, E_m]
-        return segsum_ref.segor_words(msgs, ops.edge_dst[m], n)
+        with jax.named_scope("segor"):
+            return segsum_ref.segor_words(msgs, ops.edge_dst[m], n)
 
     if mode not in ("gs", "jacobi_packed"):
         raise ValueError(f"unknown sparse mode {mode!r}")

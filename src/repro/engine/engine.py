@@ -12,9 +12,15 @@ Results carry the survivor triple mask (Sect. 5 pruning), per-variable
 candidate bindings under the query's own variable names, per-stage timings,
 and the cache/batch provenance — enough for a caller to assert the warm
 path did no recompilation.
+
+Each call and stage is a ``jax.profiler.TraceAnnotation`` span
+(``engine.batch``; ``engine.plan``/``solve``/``prune``, whose intervals are
+also what ``stage_seconds`` sums): inert until a profiler runs, then on the
+profiler's clock beside the device's own events.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import threading
@@ -22,6 +28,7 @@ import time
 from typing import Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import pruning, soi as soi_mod, sparql
 from repro.core.graph import Graph
@@ -455,6 +462,10 @@ class Engine:
         same graph version, even when the source database mutates while
         the batch is in flight.
         """
+        with TraceAnnotation("engine.batch", requests=len(prepared)):
+            return self._execute_prepared(prepared)
+
+    def _execute_prepared(self, prepared) -> list[ExecResult]:
         if self.faults is not None:
             # deterministic injection site (DESIGN.md 14.1): a poisoned
             # request raises here, on every replica it is retried on
@@ -509,15 +520,16 @@ class Engine:
             bucket = bucket_for(len(uniq), self.buckets)
         bindings = uniq + [uniq[-1]] * (bucket - len(uniq))  # pad: repeat last
 
-        t = time.perf_counter()
-        # snapshot already pinned by the caller (execute/execute_prepared)
-        plan, hit = self.plan_for(requests[0][1].template, bucket, _refresh=False)
-        t_plan = time.perf_counter() - t
+        tmpl = requests[0][1].template
+        with self._stage("plan") as st:
+            # snapshot already pinned by the caller (execute/execute_prepared)
+            plan, hit = self.plan_for(tmpl, bucket, _refresh=False)
+        t_plan = st.seconds
 
-        t = time.perf_counter()
-        warm_before = plan.metrics.warm_resumes
-        chi, sweeps = plan.execute(bindings)
-        t_solve = time.perf_counter() - t
+        with self._stage("solve", bucket=bucket, template=tmpl.span_key) as st:
+            warm_before = plan.metrics.warm_resumes
+            chi, sweeps = plan.execute(bindings)
+        t_solve = st.seconds
         with self._stats_lock:
             # one atomic commit per microbatch event, so every stats()
             # snapshot satisfies sum(engine_counts) == microbatches
@@ -530,12 +542,14 @@ class Engine:
         self._bump_stage("solve", t_solve)
 
         out: list[tuple[int, ExecResult]] = []
+        crossed = self.db.n_edges * len(plan.base_soi.pattern_edges)
         for i, consts in enumerate(uniq):
-            t = time.perf_counter()
-            chi_i = chi[plan.layout.chi_slice(i)]
-            mask, stats = pruning.prune_triples(plan.base_soi, chi_i, self.db)
-            canon_rows = soi_mod.collect(plan.base_soi, chi_i)
-            t_prune = time.perf_counter() - t
+            with self._stage("prune", triples=crossed) as st:
+                chi_i = chi[plan.layout.chi_slice(i)]
+                mask, stats = pruning.prune_triples(plan.base_soi, chi_i, self.db)
+                canon_rows = soi_mod.collect(plan.base_soi, chi_i)
+                st.span.set_metadata(survivors=stats.n_after)
+            t_prune = st.seconds
             self._bump_stage("prune", t_prune)
             for idx, inst in by_consts[consts]:
                 out.append(
@@ -559,6 +573,16 @@ class Engine:
                     )
                 )
         return out
+
+    @contextlib.contextmanager
+    def _stage(self, stage: str, **args):
+        """Span ``engine.<stage>`` around the block; ``.seconds`` is the
+        block's interval, which the caller adds to ``stage_seconds``."""
+        st = _Stage()
+        with TraceAnnotation(f"engine.{stage}", **args) as st.span:
+            t = time.perf_counter()
+            yield st
+            st.seconds = time.perf_counter() - t
 
     def _bump_stage(self, stage: str, seconds: float) -> None:
         with self._stats_lock:
@@ -595,6 +619,13 @@ class Engine:
     def metrics(self) -> EngineMetrics:
         """Alias of :meth:`stats` (the original name, kept for callers)."""
         return self.stats()
+
+
+class _Stage:
+    """What :meth:`Engine._stage` hands its block: the open span (for
+    arguments known only at the end) and, once closed, its seconds."""
+
+    __slots__ = ("span", "seconds")
 
 
 def _merge_union(partials: list[ExecResult], db: Graph) -> ExecResult:

@@ -17,6 +17,12 @@ which reproduces exactly what ``compile_soi`` does for a literal constant
 in the database).  One slot may map to *several* internal variables — the
 SOI builder gives constants a private singleton variable per BGP — so the
 scatter index list carries one entry per (instance, slot variable).
+
+A build is the profiler span ``plan.build``; an execute is ``plan.inputs``
+(constant rows and warm start up to the device), ``plan.fixpoint`` (the
+jitted solve, waited for), ``plan.copy_back`` (chi and the sweep count
+back to the host) and ``plan.memo`` (chi packed into the warm-start memo).
+On the device the solve runs under ``jax.named_scope("fixpoint")``.
 """
 from __future__ import annotations
 
@@ -28,6 +34,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import bitops, dualsim, soi as soi_mod
 from repro.core.graph import Graph, GraphDelta
@@ -100,6 +107,19 @@ class CompiledPlan:
         ``engine="auto"`` selection prices with (``None``: the persisted
         machine spec, then the hand-tuned fallback — DESIGN.md Sect. 13).
         """
+        with TraceAnnotation(
+            "plan.build", template=template.span_key, bucket=batch
+        ) as span:
+            self._build(
+                template, db, engine, batch, node_index, backend, adj_cache,
+                mesh, n_blocks, incremental, spec,
+            )
+            span.set_metadata(engine=self.engine)
+
+    def _build(
+        self, template, db, engine, batch, node_index, backend, adj_cache,
+        mesh, n_blocks, incremental, spec,
+    ) -> None:
         t0 = time.perf_counter()
         backend = backend or jax.default_backend()
         self.template = template
@@ -257,7 +277,8 @@ class CompiledPlan:
                 ops = dataclasses.replace(ops, init_packed=init)
             else:
                 ops = dataclasses.replace(ops, init=init)
-            chi, sweeps = solver(ops)
+            with jax.named_scope("fixpoint"):
+                chi, sweeps = solver(ops)
             return chi[:, :n_nodes], sweeps
 
         # the jitted fixpoint: ``fixpoint(*fixpoint_inputs(bindings))``
@@ -328,24 +349,37 @@ class CompiledPlan:
         start for exactly these constants, the solve resumes from it
         instead of the Eq.-13 init (same fixpoint, far fewer sweeps).
         """
-        ops, rows, chi0 = self.fixpoint_inputs(bindings)
-        key = tuple(bindings)
-        warm = self._warm.pop(key, None)
-        if warm is not None:
-            width = chi0.shape[-1]
-            if warm.shape[-1] != width:  # partitioned block padding
-                warm = np.pad(warm, ((0, 0), (0, width - warm.shape[-1])))
-            chi0 = jnp.asarray(warm)
-            self.metrics.warm_resumes += 1
-        chi, sweeps = self.fixpoint(ops, rows, chi0)
+        with TraceAnnotation("plan.inputs") as span:
+            ops, rows, chi0 = self.fixpoint_inputs(bindings)
+            h2d = rows.nbytes
+            key = tuple(bindings)
+            warm = self._warm.pop(key, None)
+            if warm is not None:
+                width = chi0.shape[-1]
+                if warm.shape[-1] != width:  # partitioned block padding
+                    warm = np.pad(warm, ((0, 0), (0, width - warm.shape[-1])))
+                chi0 = jnp.asarray(warm)
+                h2d += warm.nbytes
+                self.metrics.warm_resumes += 1
+            span.set_metadata(h2d_bytes=h2d)
+        with TraceAnnotation("plan.fixpoint"):
+            # waits where np.asarray below would: the copy is timed apart
+            chi, sweeps = jax.block_until_ready(self.fixpoint(ops, rows, chi0))
         self.metrics.executions += 1
-        chi, sweeps = np.asarray(chi), int(sweeps)
+        with TraceAnnotation(
+            "plan.copy_back", d2h_bytes=chi.nbytes + sweeps.nbytes
+        ) as span:
+            chi, sweeps = np.asarray(chi), int(sweeps)
+            span.set_metadata(sweeps=sweeps)
         self.last_sweeps = sweeps
         if self.incremental:
             # bit-packed: 8x smaller than the bool chi it warm-starts, and
             # for the packed-chi engines it feeds straight back into the
             # solver with no unpack round trip (DESIGN.md Sect. 9)
-            self._chi_memo[key] = bitops.pack_np(chi)
+            with TraceAnnotation("plan.memo") as span:
+                packed = bitops.pack_np(chi)
+                self._chi_memo[key] = packed
+                span.set_metadata(bytes=packed.nbytes)
         return chi, sweeps
 
     def patch_graph(

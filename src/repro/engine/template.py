@@ -20,6 +20,8 @@ from repro.core import sparql
 from repro.core.sparql import BGP, Const, Query, Triple, Var
 
 SLOT_PREFIX = "$slot"
+# a profiler span argument may hold none of these (TraceMe's own syntax)
+_SPAN_UNSAFE = str.maketrans(",#=", "___")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,6 +35,11 @@ class QueryTemplate:
 
     def __hash__(self) -> int:  # Query holds tuples of frozen dataclasses
         return hash(self.key)
+
+    @property
+    def span_key(self) -> str:
+        """``key`` as a profiler span argument (``,#=`` become ``_``)."""
+        return self.key.translate(_SPAN_UNSAFE)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -130,6 +130,7 @@ def segsum_blocks(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad, dp), vals_b.dtype),
         interpret=interpret,
+        name="segsum_blocks",
     )(win, seg_b, vals_p)
     return out[:num_segments, :d]
 
@@ -300,5 +301,6 @@ def segor_blocks(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad // 32, vp), jnp.int32),
         interpret=interpret,
+        name="segor_blocks",
     )(win, seg_b.reshape(g, 1, be), vals_p)
     return jax.lax.bitcast_convert_type(out[:nw, :v].T, jnp.uint32)

@@ -2,8 +2,8 @@
 (DESIGN.md Sect. 10.5).
 
 The serving loop is judged by its tail, not its mean: an open-loop
-saturation sweep (``benchmarks/serve_bench.py``) needs p50/p99 queue and
-end-to-end latency, shed counts *by cause*, and per-tenant throughput —
+saturation sweep (``benchmarks/serve_bench.py``) needs p50/p99 end-to-end
+latency, shed counts *by cause*, and per-tenant throughput —
 and it needs them as one *consistent* snapshot, because the dispatcher,
 the replica pool, and the benchmark reader all touch the counters from
 different threads.  Every mutation and the whole :meth:`ServeMetrics.
@@ -12,7 +12,9 @@ snapshot` copy therefore run under one lock; a reader can never observe
 
 Latencies go into fixed geometric buckets (:class:`LatencyHistogram`)
 rather than per-request lists, so a saturation run's memory cost is O(1)
-in request count and quantiles are one pass over ~40 ints.
+in request count and quantiles are one pass over ~40 ints.  Queue wait
+has no histogram: each request's own ``ServeResult.queue_ms`` is the raw
+sample, and a bucketed quantile of it would snap to bucket edges.
 """
 from __future__ import annotations
 
@@ -89,7 +91,6 @@ class MetricsSnapshot:
     queue_depth: int
     queue_peak: int
     per_tenant: dict[str, dict[str, int]]  # tenant -> submitted/completed/shed
-    queue_wait: dict[str, float]  # LatencyHistogram.summary() of queue time
     latency: dict[str, float]  # summary() of end-to-end completed latency
     service: dict[str, float]  # summary() of per-batch service time
     # failure-plane counters (ISSUE 10); defaulted so older constructors
@@ -137,7 +138,6 @@ class ServeMetrics:
         self._queue_depth = 0  # guarded-by: _lock
         self._queue_peak = 0  # guarded-by: _lock
         self._per_tenant: dict[str, dict[str, int]] = {}  # guarded-by: _lock
-        self._queue_wait = LatencyHistogram()  # guarded-by: _lock
         self._latency = LatencyHistogram()  # guarded-by: _lock
         self._service = LatencyHistogram()  # guarded-by: _lock
         self._timeouts = 0  # guarded-by: _lock
@@ -165,20 +165,17 @@ class ServeMetrics:
             self._queue_depth = depth
             self._queue_peak = max(self._queue_peak, depth)
 
-    def on_shed(self, tenant: str, cause: str, queue_s: float = 0.0) -> None:
+    def on_shed(self, tenant: str, cause: str) -> None:
         """One request shed (``cause`` in :data:`SHED_CAUSES`)."""
         with self._lock:
             self._shed[cause] += 1
             self._tenant(tenant)["shed"] += 1
-            if queue_s > 0.0:  # deadline sheds waited in queue first
-                self._queue_wait.add(queue_s)
 
-    def on_complete(self, tenant: str, queue_s: float, total_s: float) -> None:
+    def on_complete(self, tenant: str, total_s: float) -> None:
         """One admitted request finished with a result."""
         with self._lock:
             self._completed += 1
             self._tenant(tenant)["completed"] += 1
-            self._queue_wait.add(queue_s)
             self._latency.add(total_s)
 
     def on_error(self, tenant: str) -> None:
@@ -187,15 +184,13 @@ class ServeMetrics:
             self._errors += 1
             self._tenant(tenant)["errors"] += 1
 
-    def on_timeout(self, tenant: str, queue_s: float = 0.0) -> None:
+    def on_timeout(self, tenant: str) -> None:
         """One admitted request exhausted its deadline across attempts."""
         with self._lock:
             self._timeouts += 1
             self._tenant(tenant)["timeouts"] = (
                 self._tenant(tenant).get("timeouts", 0) + 1
             )
-            if queue_s > 0.0:
-                self._queue_wait.add(queue_s)
 
     def on_retry(self) -> None:
         """One batch attempt was re-dispatched to a different replica."""
@@ -244,7 +239,6 @@ class ServeMetrics:
                 queue_depth=self._queue_depth,
                 queue_peak=self._queue_peak,
                 per_tenant={t: dict(d) for t, d in self._per_tenant.items()},
-                queue_wait=self._queue_wait.summary(),
                 latency=self._latency.summary(),
                 service=self._service.summary(),
                 timeouts=self._timeouts,

@@ -58,6 +58,8 @@ import threading
 import time
 from typing import Sequence
 
+from jax.profiler import TraceAnnotation
+
 from repro.db.results import ResultSet
 from repro.distributed.fault import Heartbeat, RestartPolicy, StragglerMonitor
 from repro.engine.engine import Engine
@@ -70,6 +72,12 @@ REBUILDING = "rebuilding"
 
 #: States a replica can be routed to.
 ROUTABLE = (HEALTHY, SUSPECT)
+
+
+def ids_text(rids: Sequence[int]) -> str:
+    """Request ids as one span argument: space-separated (a TraceMe value
+    holds no ``,``, ``#`` or ``=``)."""
+    return " ".join(map(str, rids))
 
 
 class NoHealthyReplica(RuntimeError):
@@ -197,7 +205,9 @@ class ReplicaRouter:
     # ------------------------------------------------------------------ #
     # routing
     # ------------------------------------------------------------------ #
-    def route(self, exclude: Sequence[str] = ()) -> Replica:
+    def route(
+        self, exclude: Sequence[str] = (), *, rids: Sequence[int] = ()
+    ) -> Replica:
         """Pick the best routable replica and count the batch in flight.
 
         Score is ``(in_flight + 1) · relative_latency + error_penalty ·
@@ -218,6 +228,10 @@ class ReplicaRouter:
         a busy replica beats no replica.  Raises
         :class:`NoHealthyReplica` when every replica is quarantined or
         rebuilding.
+
+        Under a running profiler the choice is the zero-length span
+        ``serve.route``: the batch's request ids ``rids``, the chosen
+        ``replica`` and every candidate's ``scores``.
         """
         with self._route_lock:
             self._rr += 1
@@ -238,14 +252,26 @@ class ReplicaRouter:
                     if r.state == SUSPECT and r.in_flight == 0
                 ]
                 if suspects:
-                    rep = suspects[0]
-                    rep.in_flight += 1
-                    return rep
+                    return self._take_locked(suspects[0], cands, rids)
             k = self._rr % len(cands)
             order = cands[k:] + cands[:k]
-            rep = min(order, key=self._score_locked)
-            rep.in_flight += 1
-            return rep
+            return self._take_locked(
+                min(order, key=self._score_locked), cands, rids
+            )
+
+    # requires-lock: _route_lock
+    def _take_locked(self, rep: Replica, cands, rids) -> Replica:
+        if TraceAnnotation.is_enabled():
+            scores = " ".join(
+                f"{r.name}:{self._score_locked(r):g}" for r in cands
+            )
+            with TraceAnnotation(
+                "serve.route", rids=ids_text(rids), replica=rep.name,
+                scores=scores,
+            ):
+                pass
+        rep.in_flight += 1
+        return rep
 
     # requires-lock: _route_lock
     def _score_locked(self, r: Replica) -> float:
@@ -298,7 +324,8 @@ class ReplicaRouter:
         return self.execute_on(rep, prepared)
 
     def execute_on(
-        self, rep: Replica, prepared: Sequence
+        self, rep: Replica, prepared: Sequence, *,
+        rids: Sequence[int] = (), attempt: int = 1,
     ) -> tuple[list[ResultSet | Exception], str]:
         """Execute one prepared batch on an already-routed replica.
 
@@ -314,7 +341,17 @@ class ReplicaRouter:
         plane; per-request outcome exceptions do not.  Health reports are
         epoch-fenced: a batch that started before a rebuild cannot mark the
         rebuilt engine.  The routed slot is always released.
+
+        The call is the span ``serve.attempt`` (``rids``, ``replica``,
+        ``attempt``), and the wait for the replica's lock ``serve.lock_wait``.
         """
+        with TraceAnnotation(
+            "serve.attempt", rids=ids_text(rids), replica=rep.name,
+            attempt=attempt,
+        ):
+            return self._attempt(rep, prepared)
+
+    def _attempt(self, rep: Replica, prepared: Sequence):
         with self._route_lock:
             eng = rep.engine
             lk = rep.lock
@@ -323,7 +360,9 @@ class ReplicaRouter:
         try:
             if self._faults is not None:
                 self._faults.on_batch_start(rep.name)
-            with lk:
+            with TraceAnnotation("serve.lock_wait"):
+                lk.acquire()
+            try:
                 # the slow-fault penalty scales *solve* time only: clocking
                 # it from before the lock would multiply each batch's wait
                 # behind its predecessor's sleep — an exponential backlog
@@ -349,6 +388,8 @@ class ReplicaRouter:
                     )
                     if penalty > 0.0:
                         time.sleep(penalty)
+            finally:
+                lk.release()
         except BaseException as exc:
             self._observe(rep, epoch, time.monotonic() - t0, error=exc)
             raise

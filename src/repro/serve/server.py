@@ -51,6 +51,8 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
+import itertools
 import math
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -87,6 +89,8 @@ class ServeResult:
     ``service_ms`` the wall time of the microbatch the request rode in
     (a batch property, shared by its riders — the per-request fair share
     lives in ``result.timings``), ``total_ms`` submit-to-resolution.
+    ``request_id`` is the id :meth:`AsyncServer.submit` gave the request,
+    on every outcome; the profiler spans of its batch carry it in ``rids``.
     """
 
     outcome: str
@@ -98,6 +102,7 @@ class ServeResult:
     service_ms: float = 0.0
     total_ms: float = 0.0
     replica: str | None = None
+    request_id: int | None = None
 
     @property
     def ok(self) -> bool:
@@ -108,9 +113,10 @@ class ServeResult:
 class _Pending:
     """One admitted request waiting in the fair scheduler."""
 
-    __slots__ = ("prepared", "tenant", "t_submit", "deadline", "future")
+    __slots__ = ("rid", "prepared", "tenant", "t_submit", "deadline", "future")
 
-    def __init__(self, prepared, tenant, t_submit, deadline, future):
+    def __init__(self, rid, prepared, tenant, t_submit, deadline, future):
+        self.rid = rid
         self.prepared = prepared
         self.tenant = tenant
         self.t_submit = t_submit
@@ -214,6 +220,7 @@ class AsyncServer:
             thread_name_prefix="repro-serve",
         )
         self._cost_memo: dict[str, float] = {}  # template key -> admission cost
+        self._rids = itertools.count()  # request ids, minted in submit
         self._loop: asyncio.AbstractEventLoop | None = None
         self._wake: asyncio.Event | None = None
         self._sem: asyncio.Semaphore | None = None
@@ -283,11 +290,13 @@ class AsyncServer:
         canonicalize + O(1) checks) and must answer immediately — a shed
         request's future is already resolved when this returns.  Must be
         called on the server's event loop.  ``query`` may be text, a
-        parsed query, or a ``Q`` builder.
+        parsed query, or a ``Q`` builder.  The request's id, unique in this
+        server, is its outcome's ``request_id``.
         """
         if not self._running:
             raise RuntimeError("server is not running")
         fut: asyncio.Future[ServeResult] = self._loop.create_future()
+        rid = next(self._rids)
         now = time.monotonic()
         self.metrics.on_submit(tenant)
 
@@ -296,7 +305,7 @@ class AsyncServer:
             self.metrics.on_shed(tenant, "overloaded")
             fut.set_result(ServeResult(
                 outcome="overloaded", tenant=tenant,
-                detail=f"queue full ({self.max_queue})",
+                detail=f"queue full ({self.max_queue})", request_id=rid,
             ))
             return fut
 
@@ -308,7 +317,7 @@ class AsyncServer:
             self.metrics.on_error(tenant)
             fut.set_result(ServeResult(
                 outcome="error", tenant=tenant, error=exc,
-                detail="rejected at parse",
+                detail="rejected at parse", request_id=rid,
             ))
             return fut
 
@@ -320,6 +329,7 @@ class AsyncServer:
                 fut.set_result(ServeResult(
                     outcome="cost", tenant=tenant,
                     detail=f"estimated cost {est:.3g} > cap {self.cost_cap:.3g}",
+                    request_id=rid,
                 ))
                 return fut
 
@@ -331,10 +341,11 @@ class AsyncServer:
             self.metrics.on_shed(tenant, "deadline")
             fut.set_result(ServeResult(
                 outcome="deadline", tenant=tenant, detail="expired at admission",
+                request_id=rid,
             ))
             return fut
 
-        item = _Pending(prepared, tenant, now, now + deadline_s, fut)
+        item = _Pending(rid, prepared, tenant, now, now + deadline_s, fut)
         depth = self._scheduler.enqueue(tenant, item)
         self.metrics.on_admit(depth)
         self._wake.set()
@@ -470,18 +481,21 @@ class AsyncServer:
                 return
             attempt += 1
             remaining = min(p.deadline for p in live) - now
+            rids = [p.rid for p in live]
             try:
-                rep = self.router.route(exclude=tried)
+                rep = self.router.route(exclude=tried, rids=rids)
             except NoHealthyReplica as exc:
                 self._fail_all(live, exc, "no healthy replica")
                 return
             payload = [p.prepared for p in live]
+            run = functools.partial(
+                self.router.execute_on, prepared=payload, rids=rids,
+                attempt=attempt,
+            )
             try:
                 if self._faults is not None:
                     self._faults.on_dispatch()
-                exec_fut = self._loop.run_in_executor(
-                    self._pool, self.router.execute_on, rep, payload
-                )
+                exec_fut = self._loop.run_in_executor(self._pool, run, rep)
             except Exception as exc:
                 # the executor itself rejected the batch (pool shut down,
                 # injected reject): execute_on never ran, release here
@@ -493,7 +507,7 @@ class AsyncServer:
             t0 = time.monotonic()
             try:
                 outcomes, replica = await self._await_attempt(
-                    exec_fut, rep, tried, payload, watchdog
+                    exec_fut, rep, tried, run, rids, watchdog
                 )
             except asyncio.TimeoutError:
                 # watchdog overrun: abandon the routed attempt (its thread
@@ -548,7 +562,7 @@ class AsyncServer:
                     ))
             return
 
-    async def _await_attempt(self, exec_fut, rep, tried, payload, watchdog):
+    async def _await_attempt(self, exec_fut, rep, tried, run, rids, watchdog):
         """Await one routed attempt under its watchdog, hedging if enabled.
 
         Never cancels the executor future — a running solve cannot be
@@ -559,7 +573,8 @@ class AsyncServer:
         With hedging on and a tracked service p99, a secondary dispatch
         races the primary once it runs ``hedge_factor`` × p99 late; the
         first clean completion wins (reads are idempotent — duplicate
-        execution is safe).
+        execution is safe).  ``run(replica)`` starts the attempt of the
+        requests ``rids`` on a replica (the primary's was ``run(rep)``).
         """
         hedge_delay = self._hedge_delay() if self.hedge else None
         if hedge_delay is None or hedge_delay >= watchdog:
@@ -573,7 +588,9 @@ class AsyncServer:
         if done:
             return exec_fut.result()
         try:
-            rep2 = self.router.route(exclude=tried | {rep.name})
+            rep2 = self.router.route(
+                exclude=tried | {rep.name}, rids=rids
+            )
         except NoHealthyReplica:
             done, _ = await asyncio.wait(
                 {exec_fut}, timeout=_wait_timeout(watchdog - hedge_delay)
@@ -584,9 +601,7 @@ class AsyncServer:
         tried.add(rep2.name)  # a failed hedge shouldn't be retried on rep2
         self.metrics.on_hedge()
         try:
-            hedge_fut = self._loop.run_in_executor(
-                self._pool, self.router.execute_on, rep2, payload
-            )
+            hedge_fut = self._loop.run_in_executor(self._pool, run, rep2)
         except Exception:
             self.router.release(rep2)
             done, _ = await asyncio.wait(
@@ -696,16 +711,15 @@ class AsyncServer:
         """
         if p.future.done():
             return
+        res.request_id = p.rid
         if res.outcome == "ok":
-            self.metrics.on_complete(
-                p.tenant, res.queue_ms / 1e3, res.total_ms / 1e3
-            )
+            self.metrics.on_complete(p.tenant, res.total_ms / 1e3)
         elif res.outcome == "error":
             self.metrics.on_error(p.tenant)
         elif res.outcome == "timeout":
-            self.metrics.on_timeout(p.tenant, res.queue_ms / 1e3)
+            self.metrics.on_timeout(p.tenant)
         else:
-            self.metrics.on_shed(p.tenant, res.outcome, res.queue_ms / 1e3)
+            self.metrics.on_shed(p.tenant, res.outcome)
         p.future.set_result(res)
 
     def _fail_all(self, pendings: list[_Pending], exc, detail: str) -> None:

@@ -134,8 +134,8 @@ def test_serve_metrics_snapshot_accounting():
     m.on_shed("a", "deadline")
     m.on_admit(depth=2)
     m.on_admit(depth=1)
-    m.on_complete("a", queue_s=0.001, total_s=0.002)
-    m.on_complete("a", queue_s=0.001, total_s=0.002)
+    m.on_complete("a", total_s=0.002)
+    m.on_complete("a", total_s=0.002)
     snap = m.snapshot()
     assert snap.submitted == 4 and snap.admitted == 2
     assert snap.shed == {"overloaded": 1, "cost": 0, "deadline": 1}
