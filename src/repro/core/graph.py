@@ -1,12 +1,15 @@
 """Edge-labeled directed graphs and graph databases (paper Sect. 2).
 
 A graph is ``G = (V, Sigma, E)`` with ``E ⊆ V × Sigma × V``.  Nodes and labels
-are dictionary-encoded to dense ints.  Three physical layouts coexist:
+are dictionary-encoded to dense ints.  Four physical layouts coexist:
 
 * **triples** — ``(E, 3) int32`` array of (src, label, dst); canonical form.
 * **per-label CSR** — forward map F_a / backward map B_a (paper's adjacency
   maps) as index arrays; used by the numpy reference engines and the join
   evaluator.
+* **label blocks** — the triples stably sorted by label, with per-label
+  offsets and contiguous subject/object columns; used by pruning, which
+  crosses only the queried labels' triples.
 * **dense boolean / bit-packed adjacency** — per-label ``bool[n, n]`` or
   ``uint32[n, n/32]`` matrices; used by the MXU / Pallas engines (viable up to
   ~64k nodes per shard; the sparse edge-list engine covers DB scale).
@@ -14,7 +17,7 @@ are dictionary-encoded to dense ints.  Three physical layouts coexist:
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterable
+from typing import Iterable, NamedTuple
 
 import numpy as np
 
@@ -26,6 +29,18 @@ from . import bitops
 # engine.cost refuses the dense-layout engine tier before it gets here
 # (ISSUE 8: the RDF workload runs where this is structurally impossible).
 DENSE_ADJ_MAX_BYTES = 2 << 30
+
+
+class LabelBlocks(NamedTuple):
+    """The triples grouped by label: label ``a``'s triples are rows
+    ``order[starts[a]:starts[a + 1]]`` of ``triples``, in their original
+    order, with subjects ``src[starts[a]:starts[a + 1]]`` and objects
+    ``dst[...]`` in the same positions."""
+
+    order: np.ndarray  # (E,) intp row numbers, stably sorted by label
+    starts: np.ndarray  # (n_labels + 1,) int64 block offsets
+    src: np.ndarray  # (E,) int32 subjects in ``order``
+    dst: np.ndarray  # (E,) int32 objects in ``order``
 
 
 @dataclasses.dataclass
@@ -43,6 +58,7 @@ class Graph:
     _bwd_csr: dict | None = dataclasses.field(default=None, repr=False)
     _node_index: dict | None = dataclasses.field(default=None, repr=False)
     _label_index: dict | None = dataclasses.field(default=None, repr=False)
+    _label_blocks: LabelBlocks | None = dataclasses.field(default=None, repr=False)
 
     # ------------------------------------------------------------------ #
     # constructors
@@ -115,6 +131,32 @@ class Graph:
 
     def label_histogram(self) -> np.ndarray:
         return np.bincount(self.triples[:, 1], minlength=self.n_labels)
+
+    def label_blocks(self) -> LabelBlocks:
+        """Cached :class:`LabelBlocks` of this snapshot.
+
+        The sort key is the label column in the narrowest unsigned type
+        that holds every label id, so numpy's stable sort is a radix sort.
+        Snapshots are immutable, so the index is built once per graph; two
+        threads racing here at worst both build it, and each assigns a
+        finished tuple.
+        """
+        blocks = self._label_blocks
+        if blocks is None:
+            labels = self.triples[:, 1]
+            key = labels.astype(np.min_scalar_type(max(self.n_labels - 1, 0)))
+            order = np.argsort(key, kind="stable")
+            starts = np.zeros(self.n_labels + 1, dtype=np.int64)
+            np.cumsum(np.bincount(labels, minlength=self.n_labels),
+                      out=starts[1:])
+            blocks = LabelBlocks(
+                order=order,
+                starts=starts,
+                src=self.triples[order, 0],
+                dst=self.triples[order, 2],
+            )
+            self._label_blocks = blocks
+        return blocks
 
     # ------------------------------------------------------------------ #
     # CSR adjacency maps (paper's F^a / B^a) — numpy reference engines

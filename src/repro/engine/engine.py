@@ -542,13 +542,13 @@ class Engine:
         self._bump_stage("solve", t_solve)
 
         out: list[tuple[int, ExecResult]] = []
-        crossed = self.db.n_edges * len(plan.base_soi.pattern_edges)
         for i, consts in enumerate(uniq):
-            with self._stage("prune", triples=crossed) as st:
+            with self._stage("prune") as st:
                 chi_i = chi[plan.layout.chi_slice(i)]
                 mask, stats = pruning.prune_triples(plan.base_soi, chi_i, self.db)
                 canon_rows = soi_mod.collect(plan.base_soi, chi_i)
-                st.span.set_metadata(survivors=stats.n_after)
+                st.span.set_metadata(triples=stats.triples_crossed,
+                                     survivors=stats.n_after)
             t_prune = st.seconds
             self._bump_stage("prune", t_prune)
             for idx, inst in by_consts[consts]:
@@ -651,6 +651,7 @@ def _merge_union(partials: list[ExecResult], db: Graph) -> ExecResult:
         n_after=n_after,
         fraction_pruned=1.0 - n_after / max(db.n_edges, 1),
         per_edge_survivors=per_edge,
+        triples_crossed=sum(p.stats.triples_crossed for p in partials),
     )
     return ExecResult(
         survivors=mask,

@@ -67,6 +67,37 @@ def test_insert_extends_dictionary(db):
     assert db.node_index["Univ0"] == db.snapshot().node_names.index("Univ0")
 
 
+def test_each_snapshot_prunes_through_its_own_label_index(db):
+    q = MEMBERS_OF.format(uni="Univ0")
+    new = ("Prof0_0_0", "memberOf", "Dept0_0")
+    g0 = db.snapshot()
+    assert new not in set(db.query(q))
+    blocks0 = g0.label_blocks()
+    db.query(q)
+    assert g0.label_blocks() is blocks0  # built once per graph
+    members0 = int(np.diff(blocks0.starts)[g0.label_id("memberOf")])
+
+    assert db.insert([new]) == 1
+    g1 = db.snapshot()
+    assert g1._label_blocks is None  # a new snapshot starts without one
+    rs = db.query(q)
+    assert new in set(rs)
+    assert np.array_equal(rs.survivor_mask, _direct_mask(sparql.parse(q), g1))
+    blocks1 = g1.label_blocks()
+    assert blocks1 is not blocks0
+    assert int(np.diff(blocks1.starts)[g1.label_id("memberOf")]) == members0 + 1
+
+    assert db.delete([new]) == 1
+    g2 = db.snapshot()
+    rs = db.query(q)
+    assert new not in set(rs)
+    assert np.array_equal(rs.survivor_mask, _direct_mask(sparql.parse(q), g2))
+    assert g2.label_blocks() is not blocks1
+    # the old snapshots keep their own indexes, untouched
+    assert g0.label_blocks() is blocks0 and g1.label_blocks() is blocks1
+    assert int(np.diff(blocks0.starts)[g0.label_id("memberOf")]) == members0
+
+
 # --------------------------------------------------------------------- #
 # versioned plan invalidation (tentpole acceptance criterion)
 # --------------------------------------------------------------------- #

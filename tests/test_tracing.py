@@ -142,6 +142,18 @@ def test_stage_seconds_is_the_sum_of_engine_spans(engine_traced, stage):
     assert -1e-6 * len(mine) <= traced_s - stages[stage] <= 2e-4 * len(mine)
 
 
+def test_prune_span_counts_the_crossed_label_blocks(engine_traced):
+    spans, _ = engine_traced
+    g = synth.lubm_like(n_universities=2, seed=0)
+    hist = g.label_histogram()
+    blocks = (hist[g.label_id("subOrganizationOf")]
+              + hist[g.label_id("memberOf")])
+    assert 0 < blocks < g.n_edges
+    prunes = [s for s in spans if s.name == "engine.prune"]
+    assert prunes
+    assert all(int(s.args["triples"]) == blocks for s in prunes)
+
+
 def test_request_ids_are_unique_on_every_outcome():
     db = GraphDB(synth.lubm_like(n_universities=2, seed=0))
 
