@@ -56,6 +56,7 @@ sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 import check  # noqa: E402
 import data  # noqa: E402
 import reference  # noqa: E402
+import spans as spans_mod  # noqa: E402
 import traffic  # noqa: E402
 from stats import percentile  # noqa: E402
 from work import edge_sweep  # noqa: E402
@@ -156,6 +157,7 @@ class RunRecord:
     solves: list  # [{"key", "batch", "sweeps", "bytes"}] (traced runs)
     trace: object  # trace.Summary or None
     peaks: dict
+    spans: object = None  # the program's spans, spans.Spans (traced runs)
 
     def answered_in_window(self) -> list:
         close = self.t_open + self.seconds
@@ -435,7 +437,8 @@ def prepare(workload: str, seed: int, *, require_chip: bool = True,
     (template, bucket) plan on every replica.
 
     ``config_override`` / ``mix_override`` replace top-level keys of the
-    configuration and the mix (the tests shrink the scale with them).
+    configuration and the mix: the tests shrink the scale with them, to the
+    configuration's own ``tiny``.  A chip run passes neither.
     """
     bm = benchmark()
     wl, cfg, mix = cell(bm, workload)
@@ -479,14 +482,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     require_chip = prepare_kw.get("require_chip", True)
 
     reqs = traffic.requests(mix, ds, seed, traffic.count(mix, seconds))
-    spans = Spans(soi_by_template_key(server, mix), ds) if trace else None
+    wrappers = Spans(soi_by_template_key(server, mix), ds) if trace else None
 
     async def serve():
         async with server:
             tmp = None
             if trace:
                 tmp = tempfile.TemporaryDirectory(prefix="bench-trace-")
-                spans.install()
+                wrappers.install()
                 jax.profiler.start_trace(tmp.name)
             before = engine_totals(server)
             n_compiles = compiles.n
@@ -497,16 +500,21 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                     else contextlib.nullcontext())
             with span:
                 out = await window(server, mix, cfg, reqs, seconds)
-            summary = None
+            summary = program = None
             if trace:
                 jax.profiler.stop_trace()
-                spans.remove()
-                summary = trace_mod.reduce(trace_mod.load(tmp.name))
+                wrappers.remove()
+                t = time.monotonic()
+                profile = trace_mod.load(tmp.name)
+                summary = trace_mod.reduce(profile)
+                program = spans_mod.reduce(profile, spans_mod.op_scopes(tmp.name))
                 tmp.cleanup()
+                log(f"trace read in {time.monotonic() - t:.1f} s")
             log(f"compilations inside the window: {compiles.n - n_compiles}")
-            return out, engine_delta(before, engine_totals(server)), summary
+            return (out, engine_delta(before, engine_totals(server)), summary,
+                    program)
 
-    (recs, t_open, unresolved), delta, summary = asyncio.run(serve())
+    (recs, t_open, unresolved), delta, summary, program = asyncio.run(serve())
     stats = dev.memory_stats() or {}
     mem_peak = int(stats.get("peak_bytes_in_use", 0))
     # the reference runs on the host, after the server and its state are gone
@@ -524,9 +532,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         checks = check.compare(recs, graph, seed, control=True,
                                unresolved=unresolved)
     record = RunRecord(recs, seconds, t_open, delta,
-                       spans.solves if spans else [], summary,
+                       wrappers.solves if wrappers else [], summary,
                        peaks(dev.device_kind) if require_chip else
-                       {"hbm_bytes_per_s": 819e9})
+                       {"hbm_bytes_per_s": 819e9}, program)
     late = [r for r in recs if r.done is not None]
     log(f"{len(recs)} requests, {sum(r.ok for r in recs)} ok, "
         f"{len(late)} resolved; engine {json.dumps(delta)}")

@@ -14,8 +14,9 @@ span:
 
 and from those, the values of the per-layer metrics in :data:`METRICS`.
 
-``run.py`` does not hand its trace here yet: its ``trace.Summary`` holds
-none of this, so no cell reports these metrics (PERF.md, Open questions).
+``run.py`` reduces the trace of a traced run here once its window has
+closed and hands the :class:`Spans` to the metric readers as
+``RunRecord.spans``; ``bench/metrics/<name>.py`` reads ``METRICS[name]``.
 
 ``jax.profiler.ProfileData`` does not show an op's named-scope path.  On a
 TPU it is the ``tf_op`` stat of the op's event *metadata*

@@ -112,8 +112,26 @@ def test_every_named_piece_exists():
     for w in bm["workloads"]:
         _, cfg, mix = run.cell(bm, w["name"])
         assert callable(data.generator(cfg["generator"]))
+        # the CPU test size: keys that replace the configuration's own
+        assert cfg["tiny"] and set(cfg["tiny"]) <= set(cfg), w["config"]
         assert callable(traffic.arrivals(mix["arrivals"]).drive)
     for m in bm["per_layer"]:
         assert callable(run.reader(m["name"]))
     shutil.os.stat(run.BENCH / "peaks.json")
     assert np.isfinite(run.peaks("TPU v5 lite")["hbm_bytes_per_s"])
+
+
+def test_every_cell_reports_what_its_layer_metrics_move():
+    """A cell reports ``setup_s``, another end-to-end metric and a per-layer
+    metric, and the end-to-end metric that each of its per-layer metrics
+    moves."""
+    bm = run.benchmark()
+    names = {m["name"] for m in bm["end_to_end"]}
+    for wl in bm["workloads"]:
+        e2e = {m["name"] for m in run.cell_metrics(bm, wl, "end_to_end")}
+        layer = run.cell_metrics(bm, wl, "per_layer")
+        assert "setup_s" in e2e and len(e2e) >= 2, wl["name"]
+        assert layer, wl["name"]
+        for m in layer:
+            assert m["moves"] in names, m["name"]
+            assert m["moves"] in e2e, (wl["name"], m["name"], m["moves"])

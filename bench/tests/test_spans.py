@@ -4,6 +4,7 @@ from types import SimpleNamespace as NS
 
 import pytest
 
+import run
 import spans
 
 EDGE = "jit(_run)/fixpoint/jit(solve_sparse)/while/body/edge_bits/gather:"
@@ -74,7 +75,7 @@ def test_spans_threads_arguments_and_clipping():
     assert s.module_s["jit__run"] == pytest.approx(200e-6)
 
 
-@pytest.mark.parametrize("metric,value", [
+BY_HAND = [
     # idle [300, 600] lies under the prune union [300, 650]
     ("device.idle_in_prune_pct", 30.0),
     # attempts cover [50, 900]: idle [0, 50] + [900, 1000]
@@ -85,9 +86,20 @@ def test_spans_threads_arguments_and_clipping():
     ("plan.transfer_mb_per_batch", 7500 / 2 / 1e6),
     # the edge_bits op's 100 us of jit__run's 200 us
     ("fixpoint.edge_bits_share_pct", 50.0),
-])
+]
+
+
+@pytest.mark.parametrize("metric,value", BY_HAND)
 def test_metric_by_hand(metric, value):
     assert spans.METRICS[metric](reduced()) == pytest.approx(value)
+
+
+@pytest.mark.parametrize("metric,value", BY_HAND)
+def test_reader_hands_the_run_spans_to_the_metric(metric, value):
+    s = reduced()
+    read = run.reader(metric)
+    assert read(NS(spans=s)) == spans.METRICS[metric](s) == pytest.approx(value)
+    assert read(NS(spans=None)) is None  # an untraced run
 
 
 def test_idle_shares_fit_inside_device_idle():

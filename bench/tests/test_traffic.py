@@ -9,15 +9,22 @@ import data
 import run
 import traffic
 from stats import percentile
+from test_rehearsal import tiny
 
 SEED = 2**31 + 12345
-TINY = {"universities": 2}
+LUBM_TINY = {"universities": 2}  # the tests of LUBM's own generator
 
 
 @pytest.fixture(scope="module")
-def ds():
-    _, cfg, _ = run.cell(run.benchmark(), "lubm.broad_open")
-    return data.generate({**cfg, **TINY}, SEED)
+def datasets():
+    """One graph per configuration, at the configuration's own ``tiny``."""
+    bm = run.benchmark()
+    out = {}
+    for w in bm["workloads"]:
+        if w["config"] not in out:
+            out[w["config"]] = data.generate(tiny(run.cell(bm, w["name"])[1]),
+                                             SEED)
+    return out
 
 
 @pytest.fixture(scope="module")
@@ -27,15 +34,17 @@ def mixes():
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in run.benchmark()["workloads"]])
-def test_same_seed_same_sequence_other_seed_other(workload, ds):
-    _, _, mix = run.cell(run.benchmark(), workload)
+def test_same_seed_same_sequence_other_seed_other(workload, datasets):
+    wl, _, mix = run.cell(run.benchmark(), workload)
+    ds = datasets[wl["config"]]
     a = traffic.requests(mix, ds, SEED, 200)
     assert a == traffic.requests(mix, ds, SEED, 200)
     b = traffic.requests(mix, ds, SEED + 1, 200)
     assert [r.text for r in a] != [r.text for r in b]
 
 
-def test_every_seed_gets_the_same_work_in_another_order(ds, mixes):
+def test_every_seed_gets_the_same_work_in_another_order(datasets, mixes):
+    ds = datasets["lubm"]
     mix = mixes["broad_open"]
     a = traffic.requests(mix, ds, SEED, 300)
     b = traffic.requests(mix, ds, SEED + 7, 300)
@@ -57,8 +66,8 @@ def test_window_counts_come_from_the_arrival_process(mixes):
 
 def test_graph_does_not_depend_on_the_run_seed():
     _, cfg, _ = run.cell(run.benchmark(), "lubm.broad_open")
-    a = data.generate({**cfg, **TINY}, 1)
-    b = data.generate({**cfg, **TINY}, 2**40 + 3)
+    a = data.generate({**cfg, **LUBM_TINY}, 1)
+    b = data.generate({**cfg, **LUBM_TINY}, 2**40 + 3)
     assert np.array_equal(a.triples, b.triples)
     assert len(np.unique(a.triples, axis=0)) == len(a.triples)
     assert a.triples.max() < a.n_nodes == len(set(a.node_names))
@@ -66,7 +75,7 @@ def test_graph_does_not_depend_on_the_run_seed():
 
 def test_graph_follows_the_uba_profile():
     _, cfg, _ = run.cell(run.benchmark(), "lubm.broad_open")
-    ds = data.generate({**cfg, **TINY}, 0)
+    ds = data.generate({**cfg, **LUBM_TINY}, 0)
     t, lab = ds.triples, {n: i for i, n in enumerate(ds.label_names)}
     assert len(lab) == 18
     pr = cfg["profile"]
@@ -102,3 +111,19 @@ def test_latency_runs_from_the_due_time():
     assert rec.latency_ms == pytest.approx(250.0)
     rec.outcome = "deadline"
     assert rec.latency_ms == math.inf
+
+
+def test_the_per_layer_median_is_the_end_to_end_median():
+    """``serve.latency_p50_ms`` reads the cell's requests as
+    ``latency_p50_ms`` does, where a cell holds the median per layer."""
+    req = traffic.Request(0.0, "{ ?x p ?y }", "t", "t0")
+    recs = [run.Rec(req, due=10.0, done=10.0 + s, outcome="ok")
+            for s in (0.3, 0.1, 0.5, 0.2)]
+    rec = run.RunRecord(recs, 51.0, 10.0, {}, [], None, {})
+    read = run.reader("serve.latency_p50_ms")
+    assert read(rec) == pytest.approx(200.0)
+    assert read(rec) == run.end_to_end(rec, 1.0)["latency_p50_ms"]
+    for r in recs[:3]:
+        r.outcome = "deadline"  # failures rank last
+    assert read(rec) == run.end_to_end(rec, 1.0)["latency_p50_ms"] == 1e9
+    assert read(run.RunRecord([], 51.0, 10.0, {}, [], None, {})) is None
