@@ -586,13 +586,16 @@ def _apply_mat(chi: jax.Array, y: jax.Array, m: int, ops: Operands) -> jax.Array
 
 
 def _apply_copies(chi: jax.Array, ops: Operands) -> jax.Array:
+    """chi[l] &= chi[r] for every copy inequality l <= r (OPTIONAL's renamed
+    occurrences); its device ops run under ``jax.named_scope("copies")``."""
     if ops.copy_rhs.shape[0] == 0:
         return chi
-    n = chi.shape[-1]
-    cvals = chi[ops.copy_rhs]
-    cvals = jnp.concatenate([cvals, jnp.ones((1, n), cvals.dtype)])
-    per_var = jnp.all(cvals[ops.var_copy], axis=1)
-    return jnp.logical_and(chi, per_var)
+    with jax.named_scope("copies"):
+        n = chi.shape[-1]
+        cvals = chi[ops.copy_rhs]
+        cvals = jnp.concatenate([cvals, jnp.ones((1, n), cvals.dtype)])
+        per_var = jnp.all(cvals[ops.var_copy], axis=1)
+        return jnp.logical_and(chi, per_var)
 
 
 # numpy scalar on purpose: a jnp constant here would initialize the JAX
@@ -605,17 +608,19 @@ def _apply_copies_packed(chi_p: jax.Array, ops: Operands) -> jax.Array:
 
     The appended pad row is all-ones *including* trailing pad bits — AND is
     its identity, and chi's own pad bits are already zero, so no pad bit can
-    ever turn on (the invariant the packed convergence test relies on).
+    ever turn on (the invariant the packed convergence test relies on).  Its
+    device ops run under ``jax.named_scope("copies")``.
     """
     if ops.copy_rhs.shape[0] == 0:
         return chi_p
-    nw = chi_p.shape[-1]
-    cvals = chi_p[ops.copy_rhs]  # [C, nw]
-    cvals = jnp.concatenate([cvals, jnp.full((1, nw), _ALL_ONES)])
-    per_var = jax.lax.reduce(
-        cvals[ops.var_copy], _ALL_ONES, jax.lax.bitwise_and, (1,)
-    )  # [V, nw]
-    return jnp.bitwise_and(chi_p, per_var)
+    with jax.named_scope("copies"):
+        nw = chi_p.shape[-1]
+        cvals = chi_p[ops.copy_rhs]  # [C, nw]
+        cvals = jnp.concatenate([cvals, jnp.full((1, nw), _ALL_ONES)])
+        per_var = jax.lax.reduce(
+            cvals[ops.var_copy], _ALL_ONES, jax.lax.bitwise_and, (1,)
+        )  # [V, nw]
+        return jnp.bitwise_and(chi_p, per_var)
 
 
 def _sweep_fixpoint(

@@ -14,8 +14,9 @@ and the cache/batch provenance — enough for a caller to assert the warm
 path did no recompilation.
 
 Each call and stage is a ``jax.profiler.TraceAnnotation`` span
-(``engine.batch``; ``engine.plan``/``solve``/``prune``, whose intervals are
-also what ``stage_seconds`` sums): inert until a profiler runs, then on the
+(``engine.batch``; ``engine.union`` around a UNION request's parts and
+their merge; ``engine.plan``/``solve``/``prune``, whose intervals are also
+what ``stage_seconds`` sums): inert until a profiler runs, then on the
 profiler's clock beside the device's own events.
 """
 from __future__ import annotations
@@ -86,6 +87,8 @@ class EngineMetrics:
     resumes_declined: int = 0  # staged plans the cost model sent cold
     warm_resume_solves: int = 0  # fixpoint solves warm-started from old chi
     adj_rebuilds_saved: int = 0  # adjacency kept because its labels were untouched
+    union_requests: int = 0  # requests split into UNION parts, each solved alone
+    union_parts: int = 0  # the parts of those requests (each also a microbatch)
 
     @property
     def plan_builds(self) -> int:
@@ -216,6 +219,8 @@ class Engine:
         self._resumes_declined = 0  # guarded-by: _stats_lock
         self._warm_solves = 0  # guarded-by: _stats_lock
         self._adj_rebuilds_saved = 0  # guarded-by: _stats_lock
+        self._union_requests = 0  # guarded-by: _stats_lock
+        self._union_parts = 0  # guarded-by: _stats_lock
         self._engine_counts: dict[str, int] = {}  # guarded-by: _stats_lock
         self._stage_seconds: dict[str, float] = {}  # guarded-by: _stats_lock
         # fault-injection hook (repro.faults.BoundFaults); None disarms the
@@ -419,16 +424,22 @@ class Engine:
         t0 = time.perf_counter()
         q, t_parse = self._parse(query)
         parts = sparql.union_split(q)
+        union = len(parts) > 1
         partials = []
-        for part in parts:
-            inst = canonicalize(part)
-            partials.append(self._solve_microbatch([(0, inst)])[0][1])
-        res = _merge_union(partials, self.db)
+        with (TraceAnnotation("engine.union", parts=len(parts)) if union
+              else contextlib.nullcontext()):
+            for part in parts:
+                inst = canonicalize(part)
+                partials.append(self._solve_microbatch([(0, inst)])[0][1])
+            res = _merge_union(partials, self.db)
         res.timings["parse"] = t_parse
         res.timings["total"] = time.perf_counter() - t0
         res.timings["batch_total"] = res.timings["total"]  # batch of one
         with self._stats_lock:
             self._requests += 1
+            if union:
+                self._union_requests += 1
+                self._union_parts += len(parts)
         self._bump_stage("parse", t_parse)
         return res
 
@@ -614,6 +625,8 @@ class Engine:
                 resumes_declined=self._resumes_declined,
                 warm_resume_solves=self._warm_solves,
                 adj_rebuilds_saved=self._adj_rebuilds_saved,
+                union_requests=self._union_requests,
+                union_parts=self._union_parts,
             )
 
     def metrics(self) -> EngineMetrics:

@@ -4,8 +4,11 @@ device scopes (DESIGN.md 10.7).
 A CPU profiler trace of a small ``AsyncServer`` run must hold every span of
 the served path, nested on the executor thread that ran the batch, with
 the batch's request ids on the routing marker and the attempt; the engine's
-``stage_seconds`` must be the sum of its ``engine.*`` spans; and the
-compiled fixpoint must keep its program name and carry its named scopes.
+``stage_seconds`` must be the sum of its ``engine.*`` spans; a UNION
+request's parts must solve inside its ``engine.union`` span, which the
+engine's union counters match; and the compiled fixpoint must keep its
+program name and carry its named scopes, ``copies`` where an OPTIONAL
+adds copy inequalities.
 """
 import asyncio
 import collections
@@ -24,6 +27,10 @@ from repro.engine.template import canonicalize
 from repro.serve import AsyncServer
 
 MEMBERS_OF = "{{ ?d subOrganizationOf {uni} . ?s memberOf ?d }}"
+# two union-free parts, each solved alone at bucket 1
+UNION_OF = "{{ ?d subOrganizationOf {uni} }} UNION {{ ?s advisor ?p . ?s memberOf ?d }}"
+# ?s occurs in both sides: one copy inequality ties its optional occurrence
+OPTIONAL_OF = MEMBERS_OF + " OPTIONAL {{ ?s advisor ?p }}"
 SPANS = (
     "serve.route", "serve.attempt", "serve.lock_wait", "engine.batch",
     "engine.plan", "engine.solve", "engine.prune", "plan.inputs",
@@ -154,6 +161,44 @@ def test_prune_span_counts_the_crossed_label_blocks(engine_traced):
     assert all(int(s.args["triples"]) == blocks for s in prunes)
 
 
+@pytest.fixture(scope="module")
+def union_traced(tmp_path_factory):
+    """Two UNION requests and one BGP through one engine: (spans, stats)."""
+    eng = GraphDB(synth.lubm_like(n_universities=2, seed=0))._engine
+    out = tmp_path_factory.mktemp("union_trace")
+    jax.profiler.start_trace(str(out))
+    try:
+        eng.execute_many([UNION_OF.format(uni="Univ0"),
+                          UNION_OF.format(uni="Univ1"),
+                          MEMBERS_OF.format(uni="Univ0")])
+    finally:
+        jax.profiler.stop_trace()
+    return read_spans(out), eng.stats()
+
+
+def test_union_span_holds_its_parts_solves(union_traced):
+    spans, _ = union_traced
+    unions = [s for s in spans if s.name == "engine.union"]
+    assert [int(u.args["parts"]) for u in unions] == [2, 2]
+    solves = [s for s in spans if s.name == "engine.solve"]
+    inside = [s for s in solves if any(
+        u.thread == s.thread and u.start <= s.start and s.end <= u.end
+        for u in unions)]
+    assert len(solves) == 5 and len(inside) == 4  # the BGP's solve is outside
+
+
+def test_union_counters_count_requests_and_parts(union_traced):
+    _, m = union_traced
+    assert (m.union_requests, m.union_parts) == (2, 4)
+    # each part is a microbatch of its own, as before the counters
+    assert m.requests == 3 and m.microbatches == 5
+
+
+def test_a_bgp_batch_opens_no_union_span(engine_traced):
+    spans, _ = engine_traced
+    assert spans and not any(s.name == "engine.union" for s in spans)
+
+
 def test_request_ids_are_unique_on_every_outcome():
     db = GraphDB(synth.lubm_like(n_universities=2, seed=0))
 
@@ -175,15 +220,30 @@ def test_request_ids_are_unique_on_every_outcome():
     assert len(set(rids)) == len(rids)
 
 
-@pytest.fixture(scope="module")
-def fixpoint_hlo():
-    """The compiled fixpoint of a sparse plan: every op's ``op_name`` is its
-    full scope path, as a device trace's ``tf_op`` shows it."""
+def _compiled_fixpoint(text: str, copies: bool) -> str:
+    """The compiled fixpoint of a sparse plan of ``text``: every op's
+    ``op_name`` is its full scope path, as a device trace's ``tf_op``
+    shows it."""
     g = synth.lubm_like(n_universities=1, seed=0)
-    inst = canonicalize(sparql.parse(MEMBERS_OF.format(uni="Univ0")))
+    inst = canonicalize(sparql.parse(text.format(uni="Univ0")))
     plan = CompiledPlan(inst.template, g, engine="sparse")
+    assert bool(len(plan.csoi.copy_lhs)) == copies
     return plan.fixpoint.lower(
         *plan.fixpoint_inputs([inst.constants])).compile().as_text()
+
+
+def _scope_paths(hlo: str) -> list:
+    return re.findall(r'op_name="(jit\(_run\)/fixpoint/[^"]*)"', hlo)
+
+
+@pytest.fixture(scope="module")
+def fixpoint_hlo():
+    return _compiled_fixpoint(MEMBERS_OF, copies=False)
+
+
+@pytest.fixture(scope="module")
+def optional_hlo():
+    return _compiled_fixpoint(OPTIONAL_OF, copies=True)
 
 
 def test_fixpoint_program_keeps_its_name(fixpoint_hlo):
@@ -192,5 +252,11 @@ def test_fixpoint_program_keeps_its_name(fixpoint_hlo):
 
 @pytest.mark.parametrize("scope", ["fixpoint", "edge_bits", "segor"])
 def test_fixpoint_ops_carry_their_scopes(fixpoint_hlo, scope):
-    paths = re.findall(r'op_name="(jit\(_run\)/fixpoint/[^"]*)"', fixpoint_hlo)
+    paths = _scope_paths(fixpoint_hlo)
     assert any(scope in path.split("/") for path in paths)
+
+
+def test_copy_step_ops_carry_their_scope(optional_hlo, fixpoint_hlo):
+    assert any("copies" in p.split("/") for p in _scope_paths(optional_hlo))
+    # a BGP has no copy inequality: no op of its fixpoint is in the scope
+    assert not any("copies" in p.split("/") for p in _scope_paths(fixpoint_hlo))
